@@ -168,6 +168,7 @@ def calibrate(args) -> dict:
 
 
 def main() -> None:
+    engine.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows-small", type=int, default=16,
                     help="smaller calibration mesh edge (rows == cols)")

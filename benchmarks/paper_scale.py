@@ -26,6 +26,7 @@ sys.path.insert(0, "src")
 import numpy as np                                              # noqa: E402
 
 from repro.bench import BenchReport, Benchmark, bench_main      # noqa: E402
+from repro.core.engine import enable_compile_cache              # noqa: E402
 from repro.core import SimConfig                                # noqa: E402
 from repro.core.state import state_bytes                        # noqa: E402
 
@@ -103,6 +104,7 @@ BENCH = Benchmark(
 
 
 def main(argv=None) -> BenchReport:
+    enable_compile_cache()
     return bench_main(BENCH, argv)
 
 

@@ -271,6 +271,7 @@ BENCH = Benchmark(
 
 
 def main(argv=None) -> BenchReport:
+    engine.enable_compile_cache()
     return bench_main(BENCH, argv)
 
 

@@ -23,6 +23,7 @@ from pathlib import Path
 sys.path.insert(0, "src")
 
 from repro.bench import BenchReport, Benchmark, bench_main      # noqa: E402
+from repro.core.engine import enable_compile_cache              # noqa: E402
 
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
@@ -147,6 +148,7 @@ BENCH = Benchmark(
 
 
 def main(argv=None) -> BenchReport:
+    enable_compile_cache()
     return bench_main(BENCH, argv)
 
 

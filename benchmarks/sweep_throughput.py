@@ -40,7 +40,8 @@ sys.path.insert(0, "src")
 # expose every core as an XLA host device BEFORE jax loads: run_sweep
 # shards the scenario axis across them, so the one compiled program fills
 # the machine (the sequential baseline keeps its usual single device)
-from repro.core.engine import expose_host_devices          # noqa: E402
+from repro.core.engine import (                            # noqa: E402
+    enable_compile_cache, expose_host_devices)
 
 expose_host_devices()
 
@@ -187,6 +188,7 @@ BENCH = Benchmark(
 
 
 def main(argv=None) -> BenchReport:
+    enable_compile_cache()
     return bench_main(BENCH, argv)
 
 

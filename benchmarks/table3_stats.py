@@ -15,6 +15,7 @@ import sys
 sys.path.insert(0, "src")
 
 from repro.bench import BenchReport, Benchmark, bench_main      # noqa: E402
+from repro.core.engine import enable_compile_cache              # noqa: E402
 from repro.bench.collect import health_metrics                  # noqa: E402
 from repro.core import SimConfig, run                           # noqa: E402
 from repro.core.trace import TRACE_APPS, app_trace              # noqa: E402
@@ -65,6 +66,7 @@ BENCH = Benchmark(
 
 
 def main(argv=None) -> BenchReport:
+    enable_compile_cache()
     return bench_main(BENCH, argv)
 
 

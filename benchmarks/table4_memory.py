@@ -33,6 +33,7 @@ import jax                                                      # noqa: E402
 import numpy as np                                              # noqa: E402
 
 from repro.bench import BenchReport, Benchmark, bench_main      # noqa: E402
+from repro.core.engine import enable_compile_cache              # noqa: E402
 from repro.core import SimConfig                                # noqa: E402
 from repro.core.config import CacheConfig                       # noqa: E402
 from repro.core.state import init_state, state_bytes            # noqa: E402
@@ -167,6 +168,7 @@ BENCH = Benchmark(
 
 
 def main(argv=None) -> BenchReport:
+    enable_compile_cache()
     return bench_main(BENCH, argv)
 
 

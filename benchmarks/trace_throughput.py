@@ -19,6 +19,7 @@ import time
 sys.path.insert(0, "src")
 
 from repro.bench import BenchReport, Benchmark, bench_main      # noqa: E402
+from repro.core.engine import enable_compile_cache              # noqa: E402
 from repro.bench.collect import (                               # noqa: E402
     count_metric, ratio_metric, timing_metric)
 from repro.core import SimConfig                                # noqa: E402
@@ -117,6 +118,7 @@ BENCH = Benchmark(
 
 
 def main(argv=None) -> BenchReport:
+    enable_compile_cache()
     return bench_main(BENCH, argv)
 
 

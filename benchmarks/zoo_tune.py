@@ -149,6 +149,7 @@ def recommend(rows, flip_margin):
 
 
 def main() -> int:
+    engine.enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--zoo", default=",".join(DEFAULT_ZOOS),
                     help="comma list of zoo family specs to tune over")

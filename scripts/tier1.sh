@@ -11,7 +11,7 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-for mod in tests/test_engine.py tests/test_trace_vec.py tests/test_detectors.py tests/test_composed.py tests/test_workloads.py tests/test_zoo.py tests/test_bench.py; do
+for mod in tests/test_engine.py tests/test_trace_vec.py tests/test_detectors.py tests/test_composed.py tests/test_workloads.py tests/test_zoo.py tests/test_bench.py tests/test_tpu_compile.py; do
   [[ -f "$mod" ]] || { echo "tier1: missing $mod" >&2; exit 1; }
 done
 # docs gates: public-surface docstrings and the generated CLI page

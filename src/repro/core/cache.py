@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import List, NamedTuple, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from .config import (
     FLITS_OF,
@@ -48,8 +49,10 @@ from .state import (
 )
 
 I32 = jnp.int32
-FLITS_TABLE = jnp.asarray(FLITS_OF, I32)
-BIG = jnp.asarray(1 << 30, I32)
+# numpy constants: importing the simulator must not initialize a jax
+# backend (a process that has one holds the chip)
+FLITS_TABLE = np.asarray(FLITS_OF, np.int32)
+BIG = np.int32(1 << 30)
 
 
 class Desc(NamedTuple):
@@ -304,9 +307,9 @@ def commit_queue(s: SimState, cfg: SimConfig, descs: List[Desc]):
         ok = d.valid & (q_size + off < qp)
         drops = drops + jnp.sum((d.valid & ~ok).astype(I32))
         pkt = (pkt_ctr + off) & (cfg.pkt_wrap - 1)
-        rows.append(jnp.stack(
-            [d.typ, d.dst, d.osrc, d.tag, pkt,
-             FLITS_TABLE[jnp.clip(d.typ, 0, len(FLITS_OF) - 1)]], axis=-1))
+        nfl = jnp.asarray(FLITS_TABLE)[jnp.clip(d.typ, 0, len(FLITS_OF) - 1)]
+        rows.append(jnp.stack([d.typ, d.dst, d.osrc, d.tag, pkt, nfl],
+                              axis=-1))
         offs.append(off)
         accs.append(ok)
         off = off + ok.astype(I32)
@@ -330,7 +333,7 @@ def commit_queue(s: SimState, cfg: SimConfig, descs: List[Desc]):
 
 #: S14 — worst-case packets a handler may enqueue, by message type
 #: (REQ, RA, NACK, DA, DR, DU, WB, B2, MIG_ACK, REQ_FWD)
-NEED_TABLE = jnp.asarray([2, 1, 0, 1, 1, 0, 0, 3, 0, 2], I32)
+NEED_TABLE = np.asarray([2, 1, 0, 1, 1, 0, 0, 3, 0, 2], np.int32)
 
 
 def _l1_install_would_wb(s: SimState, cfg: SimConfig, ctx: NodeCtx,
@@ -429,7 +432,7 @@ def phase1a(s: SimState, cfg: SimConfig, ctx: NodeCtx) -> SimState:
                 + p_b2.astype(I32)                            # MIG_ACK
                 + b2_du_p)                                    # install_l2 DUs
     else:
-        need = NEED_TABLE[jnp.clip(typ, 0, 9)]
+        need = jnp.asarray(NEED_TABLE)[jnp.clip(typ, 0, 9)]
     valid = pc_valid & (s.q_size + need <= cfg.send_queue)
     if cfg.pc_depth > 1:
         # guaranteed drain: a FULL queue must make progress every cycle

@@ -75,23 +75,60 @@ __all__ = [
     "Scenario", "Bucket", "ExecutionPlan", "make_scenario", "bucket_key",
     "choose_tiling", "choose_grid", "backend_cost", "choose_backend",
     "compile_plan", "execute_plan", "plan_and_run", "load_manifest",
-    "expose_host_devices", "CostConstants", "cost_constants",
-    "set_cost_constants", "load_cost_constants", "save_cost_constants",
-    "parse_mem_budget", "plan_state_bytes",
+    "expose_host_devices", "enable_compile_cache", "CostConstants",
+    "cost_constants", "set_cost_constants", "load_cost_constants",
+    "save_cost_constants", "parse_mem_budget", "plan_state_bytes",
 ]
+
+
+def _runs_on_cpu() -> bool:
+    """Whether this process's jax will run on the CPU, decided without
+    importing jax: ``$JAX_PLATFORMS`` when set (its first entry), else
+    the absence of an installed TPU runtime."""
+    plats = os.environ.get("JAX_PLATFORMS", "").strip().lower()
+    if plats:
+        return plats.split(",")[0] == "cpu"
+    import importlib.util
+    return importlib.util.find_spec("libtpu") is None
 
 
 def expose_host_devices() -> None:
     """Expose CPU cores as XLA host devices so the sweep backend can shard
-    the scenario axis.  Must run before the first jax import; a no-op when
-    the flag is already set (so explicit user pins win) or jax is loaded."""
+    the scenario axis.  Must run before the first jax import; a no-op
+    when the run is not on the CPU (an accelerator brings its own
+    devices), when the flag is already set (so explicit user pins win) or
+    when jax is loaded."""
     import sys
-    if "jax" not in sys.modules \
+    if _runs_on_cpu() and "jax" not in sys.modules \
             and "--xla_force_host_platform_device_count" \
             not in os.environ.get("XLA_FLAGS", ""):
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={os.cpu_count()}")
+
+
+#: persistent compile cache of this checkout when
+#: ``$JAX_COMPILATION_CACHE_DIR`` is unset.  The path is part of every
+#: cache key, so it is fixed (never a temp name, a pid or a time stamp)
+#: and listed in ``.gitignore``.
+COMPILE_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compile cache before the first compile;
+    returns its directory.  ``$JAX_COMPILATION_CACHE_DIR``, when set, is
+    left to jax (which reads it itself); otherwise the cache lives at
+    :data:`COMPILE_CACHE_DIR`.  Entry points (launcher, benchmarks, chip
+    smoke) call this; tests do not."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
 
 #: SimConfig fields carried as traced per-scenario state by the batched
 #: driver (SimState.knob_*) — these never force a new bucket/compile.
@@ -700,8 +737,8 @@ def execute_plan(plan: ExecutionPlan, max_cycles: Optional[int] = None,
 
     Args:
         plan: the compiled plan.  A spatial/composed bucket planned for
-            more devices than this process has degrades to the dense
-            sweep backend instead of crashing.
+            more devices than this process has raises ``RuntimeError``
+            (re-plan with this process's device count instead).
         max_cycles: per-scenario cycle cap (default: each scenario's
             ``cfg.max_cycles``).
         chunk: sweep-backend cycles per in-graph termination check.
@@ -713,13 +750,14 @@ def execute_plan(plan: ExecutionPlan, max_cycles: Optional[int] = None,
     out: List[Optional[Dict[str, int]]] = [None] * len(plan.scenarios)
     for b in plan.buckets:
         if b.backend in ("sharded", "composed"):
-            # the plan may have been compiled for a different ndev than
-            # this process actually has; degrade to the dense backend
-            # rather than crash on a short device list
             import jax
-            if len(jax.devices()) < b.devices_needed:
-                res = _run_bucket_sweep(b, max_cycles, chunk)
-            elif b.backend == "sharded":
+            have = len(jax.devices())
+            if have < b.devices_needed:
+                raise RuntimeError(
+                    f"{b.backend} bucket {b.cfg.rows}x{b.cfg.cols} was "
+                    f"planned for {b.devices_needed} devices, this process "
+                    f"has {have}; compile the plan for {have}")
+            if b.backend == "sharded":
                 res = _run_bucket_sharded(b, max_cycles, sharded_chunk)
             else:
                 res = _run_bucket_composed(b, max_cycles, sharded_chunk)

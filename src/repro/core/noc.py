@@ -18,6 +18,7 @@ from __future__ import annotations
 from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from .config import NUM_PORTS, SimConfig
 from .state import (
@@ -30,7 +31,7 @@ from .state import (
 
 I32 = jnp.int32
 #: larger than any node id or packet counter (pkt wraps at 2**30)
-BIG = jnp.asarray(1 << 30, I32)
+BIG = np.int32(1 << 30)
 
 
 class ArbResult(NamedTuple):

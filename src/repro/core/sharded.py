@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import inspect
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
@@ -49,19 +48,6 @@ from .state import (NUM_F, NodeCtx, SimState, fold_stats, init_state,
 
 __all__ = ["ShardedSim", "run_composed", "make_sharded_step", "to_grid",
            "state_specs", "make_geo_arrays"]
-
-# jax >= 0.5 exports shard_map at the top level; 0.4.x keeps it in
-# experimental.  The replication-check kwarg was also renamed
-# (check_rep -> check_vma); stats leave the tile replicated but become
-# device-varying inside the scan (re-replicated via psum), so the check
-# must be off either way.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-_SM_NOCHECK = {
-    ("check_vma" if "check_vma" in inspect.signature(_shard_map).parameters
-     else "check_rep"): False}
 
 I32 = jnp.int32
 
@@ -112,14 +98,13 @@ def state_specs(cfg: SimConfig, row_axes, col_axes,
 
 
 def _halo_transfer(out4: jnp.ndarray, vp4: jnp.ndarray,
-                   row_axes, col_axes, nrow: int, ncol: int) -> jnp.ndarray:
+                   row_axes, col_axes) -> jnp.ndarray:
     """Phase-3 transfer for one ``(…, Rt, Ct, 4, F)`` tile with ppermute
     halos.  Leading batch dims (the composed backend's local scenario
     axis) ride along unchanged — each directional halo slab is ONE
-    ``ppermute`` regardless of batch size.
-
-    ``nrow``/``ncol`` are the static tile-grid sizes (taken from the mesh
-    by the caller — ``jax.lax.axis_size`` is unavailable on jax 0.4.x)."""
+    ``ppermute`` regardless of batch size."""
+    nrow = jax.lax.axis_size(row_axes)
+    ncol = jax.lax.axis_size(col_axes)
     perm_dn = [(i, (i + 1) % nrow) for i in range(nrow)]
     perm_up = [(i, (i - 1) % nrow) for i in range(nrow)]
     perm_rt = [(i, (i + 1) % ncol) for i in range(ncol)]
@@ -183,8 +168,6 @@ def make_sharded_step(cfg: SimConfig, mesh,
     gspec = (P(row_axes, col_axes), P(row_axes, col_axes),
              P(row_axes, col_axes), P(row_axes, col_axes))
     spatial_axes = tuple(row_axes) + tuple(col_axes)
-    nrow = int(np.prod([mesh.shape[a] for a in row_axes]))
-    ncol = int(np.prod([mesh.shape[a] for a in col_axes]))
     batched = bool(batch_axes)
 
     # sim.finished reduces over every axis when `cycle` is scalar, so it
@@ -211,15 +194,13 @@ def make_sharded_step(cfg: SimConfig, mesh,
             s, arb = jax.vmap(p12)(flat)
             bl = s.st.shape[0]
             out4 = arb.out.reshape(bl, rt, ct, 4, NUM_F)
-            inp_next = _halo_transfer(out4, vp4, row_axes, col_axes,
-                                      nrow, ncol)
+            inp_next = _halo_transfer(out4, vp4, row_axes, col_axes)
             s = jax.vmap(lambda ss, ab, ip: deliver(ss, cfg, ctx, ab, ip))(
                 s, arb, inp_next.reshape(bl, rt * ct, 4, NUM_F))
         else:
             s, arb = p12(flat)
             out4 = arb.out.reshape(rt, ct, 4, NUM_F)
-            inp_next = _halo_transfer(out4, vp4, row_axes, col_axes,
-                                      nrow, ncol)
+            inp_next = _halo_transfer(out4, vp4, row_axes, col_axes)
             s = deliver(s, cfg, ctx, arb, inp_next.reshape(rt * ct, 4, NUM_F))
         return narrow_state(s._replace(cycle=s.cycle + 1), dtypes)
 
@@ -251,7 +232,7 @@ def make_sharded_step(cfg: SimConfig, mesh,
         # the dense driver's per-cycle fold bit for bit at chunk edges.
         in_stats, in_hi = flat.stats, flat.stats_hi
 
-        nspat = jax.lax.psum(jnp.ones((), I32), spatial_axes)
+        nspat = jax.lax.axis_size(spatial_axes)
 
         def body(carry, _):
             fin_local = tile_finished(carry)        # () solo | (Bl,) batched
@@ -277,12 +258,15 @@ def make_sharded_step(cfg: SimConfig, mesh,
 
     def build(n_cycles: int):
         if n_cycles not in cache:
-            smapped = _shard_map(
+            # stats leave the tile replicated but become device-varying
+            # inside the scan (re-replicated via psum), so the
+            # replication check must be off
+            smapped = jax.shard_map(
                 functools.partial(step_tile, n_cycles),
                 mesh=mesh,
                 in_specs=(sspec,) + gspec,
                 out_specs=sspec,
-                **_SM_NOCHECK,
+                check_vma=False,
             )
             # donate the state (arg 0): in/out shardings and dtypes match
             # leaf for leaf, so XLA updates the mesh in place instead of

@@ -32,14 +32,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, block_q,
 
     def body(kj, carry):
         m, l, acc = carry
-        # leading unit dims indexed with dslice(0, 1): plain python ints in
-        # a pl.load index tuple crash interpret mode on jax 0.4.x
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(0, 1),
-                            pl.dslice(kj * block_k, block_k),
-                            slice(None)))[0, 0].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(0, 1),
-                            pl.dslice(kj * block_k, block_k),
-                            slice(None)))[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
+        v = v_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
         s = q @ k.T                                      # (block_q, block_k)
         if causal:
             qpos = qi * block_q + jax.lax.broadcasted_iota(

@@ -200,6 +200,7 @@ def main(argv=None) -> None:
     from repro.core import engine
     if args.sweep or args.plan or args.zoo:
         engine.expose_host_devices()
+    engine.enable_compile_cache()
 
     force = args.backend if args.backend != "auto" else None
     if args.sharded:

@@ -237,21 +237,19 @@ def test_forced_composed_falls_back_on_one_device():
 
 
 def test_composed_plan_on_short_device_list_degrades():
-    """A composed plan compiled for 8 devices must still execute on this
-    1-device process — via the sweep backend — and stay bit-exact."""
-    from repro.core.sim import run
-    from repro.core.trace import app_trace
-
+    """A composed plan compiled for more devices than this process has
+    refuses to run rather than silently swapping in the sweep backend."""
+    import jax
     base = SimConfig(rows=4, cols=4, addr_bits=14,
                      centralized_directory=False)
     scs = [engine.make_scenario(base, app="matmul", seed=s,
                                 refs_per_core=10) for s in range(2)]
-    plan = engine.compile_plan(scs, ndev=8, force_backend="composed")
-    assert plan.buckets[0].backend == "composed"
-    got = engine.execute_plan(plan, chunk=4)
-    ref = [run(sc.cfg, app_trace(sc.cfg, sc.app, sc.refs_per_core, sc.seed),
-               chunk=4) for sc in scs]
-    assert got == ref
+    plan = engine.compile_plan(scs, ndev=2 * len(jax.devices()),
+                               force_backend="composed")
+    b = plan.buckets[0]
+    assert b.backend == "composed" and b.devices_needed > len(jax.devices())
+    with pytest.raises(RuntimeError, match="planned for"):
+        engine.execute_plan(plan, chunk=4)
 
 
 # ---------------------------------------------------------------------------

@@ -122,15 +122,17 @@ def test_forced_sharded_falls_back_on_one_device():
 
 def test_sharded_plan_on_short_device_list_degrades():
     """A plan compiled for more devices than the process has (ndev is a
-    caller-supplied compile parameter) must still execute — via the dense
-    backend — and stay bit-exact."""
+    caller-supplied compile parameter) refuses to run rather than
+    silently swapping in the dense backend."""
+    import jax
     base = SimConfig(rows=4, cols=4, addr_bits=14,
                      centralized_directory=False)
     sc = engine.make_scenario(base, app="matmul", seed=1, refs_per_core=10)
-    plan = engine.compile_plan([sc], ndev=4, force_backend="sharded")
-    assert plan.buckets[0].backend == "sharded"     # planned for 4 devices
-    got = engine.execute_plan(plan, chunk=4)        # ...but we have 1
-    assert got == [solo_reference(sc)]
+    ndev = 2 * len(jax.devices())
+    plan = engine.compile_plan([sc], ndev=ndev, force_backend="sharded")
+    assert plan.buckets[0].backend == "sharded"     # planned for 2x devices
+    with pytest.raises(RuntimeError, match="planned for"):
+        engine.execute_plan(plan, chunk=4)          # ...but we have half
 
 
 def test_manifest_loading():
