@@ -35,6 +35,7 @@ MODULES = [
     "repro.core.config",
     "repro.core.workloads",
     "repro.core.zoo",
+    "repro.core.tracing",
 ]
 
 #: (module, symbol): every signature parameter must appear in the

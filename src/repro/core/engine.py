@@ -69,6 +69,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from .config import CacheConfig, SimConfig
+from .tracing import span
 from .workloads import source_summary, valid_source
 
 __all__ = [
@@ -627,6 +628,7 @@ class ExecutionPlan:
         }
 
 
+@span("repro.plan")
 def compile_plan(scenarios: Sequence[Scenario], ndev: Optional[int] = None,
                  force_backend: Optional[str] = None,
                  mem_budget: Optional[int] = None) -> ExecutionPlan:

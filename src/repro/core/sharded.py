@@ -45,6 +45,7 @@ from .sim import (ABORT_LIVELOCK, ExecAux, _PROG_IDX, check_cycle_cap,
                   diag_counts, finished as _finished, stats_list)
 from .state import (NUM_F, NodeCtx, SimState, fold_stats, init_state,
                     leaf_dtypes, make_geometry, narrow_state, widen_state)
+from .tracing import span
 
 __all__ = ["ShardedSim", "run_composed", "make_sharded_step", "to_grid",
            "state_specs", "make_geo_arrays"]
@@ -185,25 +186,37 @@ def make_sharded_step(cfg: SimConfig, mesh,
         flat = widen_state(flat)
 
         def p12(fs):
-            s = phase1a(fs, cfg, ctx)
-            s = phase1b(s, cfg, ctx)
-            return phase2(s, cfg, ctx)
+            with jax.named_scope("phase1a"):
+                s = phase1a(fs, cfg, ctx)
+            with jax.named_scope("phase1b"):
+                s = phase1b(s, cfg, ctx)
+            with jax.named_scope("phase2"):
+                return phase2(s, cfg, ctx)
 
         vp4 = ctx.valid_port.reshape(rt, ct, 4)
         if batched:
             s, arb = jax.vmap(p12)(flat)
             bl = s.st.shape[0]
             out4 = arb.out.reshape(bl, rt, ct, 4, NUM_F)
-            inp_next = _halo_transfer(out4, vp4, row_axes, col_axes)
-            s = jax.vmap(lambda ss, ab, ip: deliver(ss, cfg, ctx, ab, ip))(
-                s, arb, inp_next.reshape(bl, rt * ct, 4, NUM_F))
+            with jax.named_scope("halo"):
+                inp_next = _halo_transfer(out4, vp4, row_axes, col_axes)
+            with jax.named_scope("phase3"):
+                s = jax.vmap(
+                    lambda ss, ab, ip: deliver(ss, cfg, ctx, ab, ip))(
+                        s, arb, inp_next.reshape(bl, rt * ct, 4, NUM_F))
         else:
             s, arb = p12(flat)
             out4 = arb.out.reshape(rt, ct, 4, NUM_F)
-            inp_next = _halo_transfer(out4, vp4, row_axes, col_axes)
-            s = deliver(s, cfg, ctx, arb, inp_next.reshape(rt * ct, 4, NUM_F))
+            with jax.named_scope("halo"):
+                inp_next = _halo_transfer(out4, vp4, row_axes, col_axes)
+            with jax.named_scope("phase3"):
+                s = deliver(s, cfg, ctx, arb,
+                            inp_next.reshape(rt * ct, 4, NUM_F))
         return narrow_state(s._replace(cycle=s.cycle + 1), dtypes)
 
+    # everything outside the phases and the halo (the psum of the
+    # finished flags, the freeze select, the stats fold) is `driver`
+    @jax.named_scope("driver")
     def step_tile(n_cycles: int, sg: SimState, nid2, nr2, nc2, vp2):
         lead = 1 if batched else 0
         rt, ct = sg.st.shape[lead], sg.st.shape[lead + 1]
@@ -402,21 +415,24 @@ class ShardedSim:
             # shorter tail program compiles once and is cached)
             n_step = min(chunk, limit - cyc)
             self.state = self.build_step(n_step)(self.state, *self.geo)
-            if bool(self._finished(self.state)):
-                break
-            prog = tuple(np.asarray(self.state.stats)[_PROG_IDX].tolist())
-            if prog == prev_prog:
-                frozen += n_step
-            else:
-                prev_prog, frozen = prog, 0
-            if lw and frozen >= lw:
-                abort = ABORT_LIVELOCK
-                break
+            with span("repro.host_monitor"):
+                if bool(self._finished(self.state)):
+                    break
+                prog = tuple(
+                    np.asarray(self.state.stats)[_PROG_IDX].tolist())
+                if prog == prev_prog:
+                    frozen += n_step
+                else:
+                    prev_prog, frozen = prog, 0
+                if lw and frozen >= lw:
+                    abort = ABORT_LIVELOCK
+                    break
         s = self.state
         z = np.int32(0)
         if abort:
-            d = diag_counts(np.asarray(s.st), np.asarray(s.inp),
-                             np.asarray(s.q_size))
+            with span("repro.host_monitor"):
+                d = diag_counts(np.asarray(s.st), np.asarray(s.inp),
+                                np.asarray(s.q_size))
             aux = ExecAux(
                 abort=np.int32(abort),
                 abort_cycle=np.asarray(s.cycle, np.int32),
@@ -456,33 +472,34 @@ class ShardedSim:
                 break
             n_step = min(chunk, limit - cyc)
             self.state = self.build_step(n_step)(self.state, *self.geo)
-            # one predicate evaluation per chunk: this post-step vector
-            # is both the monitor's not-finished guard and the next
-            # iteration's activity mask
-            fin = np.asarray(self._finished(self.state))
-            if not lw:
-                continue
-            stats = np.asarray(self.state.stats)
-            stats_hi = np.asarray(self.state.stats_hi)
-            cyc_now = np.asarray(self.state.cycle)
-            st = inp = qs = None
-            for b in np.nonzero(active)[0]:
-                prog = stats[b, _PROG_IDX].tobytes()
-                if prog == prev_prog[b]:
-                    frozen[b] += n_step
-                else:
-                    prev_prog[b], frozen[b] = prog, 0
-                if frozen[b] >= lw and not fin[b]:
-                    abort[b] = ABORT_LIVELOCK
-                    ab_cycle[b] = int(cyc_now[b])
-                    ab_stats[b] = stats[b]
-                    ab_hi[b] = stats_hi[b]
-                    if st is None:   # pull the big arrays at most once
-                        st = np.asarray(self.state.st)
-                        inp = np.asarray(self.state.inp)
-                        qs = np.asarray(self.state.q_size)
-                    for k, v in diag_counts(st[b], inp[b], qs[b]).items():
-                        diag[k][b] = v
+            with span("repro.host_monitor"):
+                # one predicate evaluation per chunk: this post-step vector
+                # is both the monitor's not-finished guard and the next
+                # iteration's activity mask
+                fin = np.asarray(self._finished(self.state))
+                if not lw:
+                    continue
+                stats = np.asarray(self.state.stats)
+                stats_hi = np.asarray(self.state.stats_hi)
+                cyc_now = np.asarray(self.state.cycle)
+                st = inp = qs = None
+                for b in np.nonzero(active)[0]:
+                    prog = stats[b, _PROG_IDX].tobytes()
+                    if prog == prev_prog[b]:
+                        frozen[b] += n_step
+                    else:
+                        prev_prog[b], frozen[b] = prog, 0
+                    if frozen[b] >= lw and not fin[b]:
+                        abort[b] = ABORT_LIVELOCK
+                        ab_cycle[b] = int(cyc_now[b])
+                        ab_stats[b] = stats[b]
+                        ab_hi[b] = stats_hi[b]
+                        if st is None:   # pull the big arrays at most once
+                            st = np.asarray(self.state.st)
+                            inp = np.asarray(self.state.inp)
+                            qs = np.asarray(self.state.q_size)
+                        for k, v in diag_counts(st[b], inp[b], qs[b]).items():
+                            diag[k][b] = v
         aux = ExecAux(abort=abort, abort_cycle=ab_cycle, abort_stats=ab_stats,
                       abort_stats_hi=ab_hi,
                       circ=diag["circ"], wait_dir=diag["wait_dir"],

@@ -52,6 +52,7 @@ from .state import (F_DST, F_VALID, P_VALID, R_NFL, Geometry, NodeCtx,
                     SimState, fold_stats, init_state, leaf_dtypes,
                     make_geometry, make_node_ctx, narrow_state, stats_totals,
                     widen_state)
+from .tracing import span
 
 __all__ = ["cycle_step", "finished", "run", "stats_list", "ExecAux",
            "VectorSim", "ABORT_LABELS", "diag_counts", "check_cycle_cap",
@@ -128,10 +129,14 @@ def cycle_step(s: SimState, cfg: SimConfig, geo: Geometry,
     counters cannot wrap at 43k nodes x long runs."""
     dtypes = leaf_dtypes(cfg, s.trace.shape[-1])
     s = widen_state(s)
-    s = phase1a(s, cfg, ctx)
-    s = phase1b(s, cfg, ctx)
-    s, arb = phase2(s, cfg, ctx)
-    s = phase3(s, cfg, geo, ctx, arb)
+    with jax.named_scope("phase1a"):
+        s = phase1a(s, cfg, ctx)
+    with jax.named_scope("phase1b"):
+        s = phase1b(s, cfg, ctx)
+    with jax.named_scope("phase2"):
+        s, arb = phase2(s, cfg, ctx)
+    with jax.named_scope("phase3"):
+        s = phase3(s, cfg, geo, ctx, arb)
     hi, lo = fold_stats(s.stats_hi, s.stats)
     return narrow_state(
         s._replace(cycle=s.cycle + 1, stats=lo, stats_hi=hi), dtypes)
@@ -233,6 +238,7 @@ def _mon_update(mon: _Mon, st: SimState, active: jnp.ndarray,
 
 
 @functools.partial(jax.jit, static_argnums=(1, 3), donate_argnums=(0,))
+@jax.named_scope("driver")
 def _run_jit(s: SimState, cfg: SimConfig, max_cycles: jnp.ndarray, chunk: int):
     """Drive a state to completion in one compiled loop; returns
     ``(state, ExecAux)``.
@@ -258,6 +264,9 @@ def _run_jit(s: SimState, cfg: SimConfig, max_cycles: jnp.ndarray, chunk: int):
     saturation monitors) likewise keep stepping; their reported statistics
     come from the ``ExecAux`` snapshot taken at the abort cycle, so results
     are independent of when the loop exits.
+
+    Everything outside the four phases of ``cycle_step`` runs under the
+    device scope ``driver`` (:mod:`repro.core.tracing`).
     """
     solo = s.cycle.ndim == 0
     if solo:
@@ -313,6 +322,7 @@ def _run_jit(s: SimState, cfg: SimConfig, max_cycles: jnp.ndarray, chunk: int):
     return fs, aux
 
 
+@span("repro.readback")
 def stats_list(s: SimState, aux: ExecAux) -> List[Dict[str, int]]:
     """Per-scenario statistics dicts from a driven state + its ExecAux.
 

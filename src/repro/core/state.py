@@ -37,6 +37,7 @@ import numpy as np
 
 from .config import NUM_MSG_TYPES, NUM_PORTS, SimConfig
 from .ref_serial import STAT_NAMES
+from .tracing import span
 
 # flit fields
 F_VALID, F_AGE, F_SRC, F_DST, F_OSRC, F_TYP, F_TAG, F_PKT, F_FID, F_NFL = range(10)
@@ -159,6 +160,7 @@ def dir_shape(cfg: SimConfig) -> Tuple[int, ...]:
     return (cfg.num_nodes, per + 1)
 
 
+@span("repro.place_state")
 def init_state(cfg: SimConfig, trace: np.ndarray) -> SimState:
     """Build the initial state.
 

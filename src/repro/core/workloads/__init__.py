@@ -25,6 +25,7 @@ from typing import Optional
 import numpy as np
 
 from ..config import SimConfig
+from ..tracing import span
 from .base import (Param, TrafficGen, gen_names, get_gen, parse_source,
                    register, resolve, source_help, source_summary,
                    valid_source)
@@ -41,6 +42,7 @@ __all__ = [
 ]
 
 
+@span("repro.trace_synthesis")
 def resolve_trace(cfg: SimConfig, app: str, refs_per_core: int,
                   seed: int) -> np.ndarray:
     """Trace-source dispatch shared by every scenario consumer.
@@ -60,6 +62,7 @@ def valid_app(app: str) -> bool:
     return valid_source(app)
 
 
+@span("repro.trace_synthesis")
 def stacked_traces(cfg: SimConfig, specs, default_refs: int = 200) -> np.ndarray:
     """Stack per-scenario traces into one ``(B, num_nodes, M)`` block for
     the batched sweep engine (:mod:`repro.core.sweep`).
