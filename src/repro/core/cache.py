@@ -46,6 +46,8 @@ from .state import (
     P_VALID,
     SimState,
     bump,
+    node_get,
+    node_set,
 )
 
 I32 = jnp.int32
@@ -128,19 +130,17 @@ def dir_write(dir_loc: jnp.ndarray, cfg: SimConfig, tag: jnp.ndarray,
 def l2_probe(s: SimState, cfg: SimConfig, tag2: jnp.ndarray):
     """Returns (set_idx, hit_way, hit) for an L2 associative probe."""
     ca = cfg.cache
-    node = jnp.arange(tag2.shape[0], dtype=I32)
     si = jnp.where(tag2 >= 0, tag2 % ca.l2_sets, 0)
-    tags = s.l2_tag[node, si]                     # (Nl, W2)
+    tags = node_get(s.l2_tag, si)                 # (Nl, W2)
     hm = (tags == tag2[:, None]) & (tag2[:, None] >= 0)
     return si, jnp.argmax(hm, axis=1).astype(I32), jnp.any(hm, axis=1)
 
 
 def l1_probe(s: SimState, cfg: SimConfig, addr: jnp.ndarray):
     ca = cfg.cache
-    node = jnp.arange(addr.shape[0], dtype=I32)
     tag1 = jnp.where(addr >= 0, addr >> ca.l1_shift, -1)
     si = jnp.where(tag1 >= 0, tag1 % ca.l1_sets, 0)
-    tags = s.l1_tag[node, si]
+    tags = node_get(s.l1_tag, si)
     hm = (tags == tag1[:, None]) & (tag1[:, None] >= 0)
     return tag1, si, jnp.argmax(hm, axis=1).astype(I32), jnp.any(hm, axis=1)
 
@@ -169,17 +169,15 @@ class L2Install(NamedTuple):
 def install_l2(s: SimState, cfg: SimConfig, ctx: NodeCtx, mask: jnp.ndarray,
                tag2: jnp.ndarray) -> L2Install:
     """S5 — masked L2 install with victim eviction + directory maintenance."""
-    ca = cfg.cache
     n = ctx.node_id.shape[0]
-    node = jnp.arange(n, dtype=I32)
     nid = ctx.node_id
     si, hw, present_any = l2_probe(s, cfg, jnp.where(mask, tag2, -1))
     present = mask & present_any
     need = mask & ~present
 
-    tags = s.l2_tag[node, si]                        # (Nl, W2)
-    migf = s.l2_mig[node, si]
-    lru = s.l2_lru[node, si]
+    tags = node_get(s.l2_tag, si)                 # (Nl, W2)
+    migf = node_get(s.l2_mig, si)
+    lru = node_get(s.l2_lru, si)
     inv = tags < 0
     has_inv = jnp.any(inv, axis=1)
     inv_way = jnp.argmax(inv, axis=1).astype(I32)
@@ -190,7 +188,7 @@ def install_l2(s: SimState, cfg: SimConfig, ctx: NodeCtx, mask: jnp.ndarray,
     fail = need & ~has_inv & all_mig
     do = need & ~fail
     vic_valid = do & ~has_inv
-    vtag = tags[node, vic_way]
+    vtag = node_get(tags, vic_way)
 
     # victim directory delete (S4)
     homev = dir_home_v(cfg, vtag, s.knob_central)
@@ -202,15 +200,11 @@ def install_l2(s: SimState, cfg: SimConfig, ctx: NodeCtx, mask: jnp.ndarray,
                     jnp.full(n, -1, I32), vtag)
 
     # write the new block
-    upd = do
-    l2_tag = s.l2_tag.at[node, si, vic_way].set(
-        jnp.where(upd, tag2, s.l2_tag[node, si, vic_way]))
-    l2_mig = s.l2_mig.at[node, si, vic_way].set(
-        jnp.where(upd, 0, s.l2_mig[node, si, vic_way]))
-    l2_last = s.l2_last.at[node, si, vic_way].set(
-        jnp.where(upd, -1, s.l2_last[node, si, vic_way]))
-    l2_streak = s.l2_streak.at[node, si, vic_way].set(
-        jnp.where(upd, 0, s.l2_streak[node, si, vic_way]))
+    at = (si, vic_way)
+    l2_tag = node_set(s.l2_tag, at, do, tag2)
+    l2_mig = node_set(s.l2_mig, at, do, 0)
+    l2_last = node_set(s.l2_last, at, do, -1)
+    l2_streak = node_set(s.l2_streak, at, do, 0)
 
     # new-owner directory update
     homen = dir_home_v(cfg, tag2, s.knob_central)
@@ -246,22 +240,21 @@ def install_l1(s: SimState, cfg: SimConfig, ctx: NodeCtx, mask: jnp.ndarray,
     """S3 — masked L1 install with victim write-back."""
     ca = cfg.cache
     n = ctx.node_id.shape[0]
-    node = jnp.arange(n, dtype=I32)
     nid = ctx.node_id
     tag1, si, hw, present_any = l1_probe(s, cfg, jnp.where(mask, addr, -1))
     present = mask & present_any
     need = mask & ~present
 
-    tags = s.l1_tag[node, si]
-    lru = s.l1_lru[node, si]
+    tags = node_get(s.l1_tag, si)
+    lru = node_get(s.l1_lru, si)
     inv = tags < 0
     has_inv = jnp.any(inv, axis=1)
     inv_way = jnp.argmax(inv, axis=1).astype(I32)
     lru_way = jnp.argmin(lru, axis=1).astype(I32)
     vic_way = jnp.where(has_inv, inv_way, lru_way)
     vic_valid = need & ~has_inv
-    vtag1 = tags[node, vic_way]
-    vowner = s.l1_owner[node, si, vic_way]
+    vtag1 = node_get(tags, vic_way)
+    vowner = node_get(s.l1_owner, (si, vic_way))
     vtag2 = jnp.where(vtag1 >= 0, vtag1 >> (ca.l2_shift - ca.l1_shift), -1)
 
     # local write-back: does our own L2 still hold the victim's block?
@@ -273,10 +266,8 @@ def install_l1(s: SimState, cfg: SimConfig, ctx: NodeCtx, mask: jnp.ndarray,
 
     way = jnp.where(present, hw, vic_way)
     w = present | need
-    l1_tag = s.l1_tag.at[node, si, way].set(
-        jnp.where(w, tag1, s.l1_tag[node, si, way]))
-    l1_owner = s.l1_owner.at[node, si, way].set(
-        jnp.where(w, owner, s.l1_owner[node, si, way]))
+    l1_tag = node_set(s.l1_tag, (si, way), w, tag1)
+    l1_owner = node_set(s.l1_owner, (si, way), w, owner)
     return L1Install(l1_tag, l1_owner, si, way, w, desc_wb,
                      jnp.sum(wb_remote.astype(I32)), n_wb_miss)
 
@@ -289,18 +280,16 @@ def commit_queue(s: SimState, cfg: SimConfig, descs: List[Desc]):
     """Append descriptors (in slot order = serial enqueue order) to the
     per-node packet ring buffer; whole packets are dropped when full.
 
-    Single batched scatter: descriptor d_i lands at ring offset equal to
-    the number of earlier accepted descriptors; rejected/invalid rows are
-    routed to the sink slot (index ``send_queue``) so indices never
-    collide.  (Perf iteration C1: was 3 sequential full-array scatter
-    rounds per phase — 2x the q_desc HBM traffic of the batched form.)
+    Descriptor d_i lands at ring offset equal to the number of earlier
+    accepted descriptors, one slot select per descriptor
+    (:func:`repro.core.state.node_set`); a rejected or invalid
+    descriptor writes nothing.  The sink slot (index ``send_queue``) is
+    never written or read — injection only indexes ``q_head % qp``.
     """
     n = s.q_size.shape[0]
-    node = jnp.arange(n, dtype=I32)
     qp = cfg.send_queue
-    q_size, pkt_ctr = s.q_size, s.pkt_ctr
+    q_size, pkt_ctr, q_desc = s.q_size, s.pkt_ctr, s.q_desc
 
-    offs, accs, rows = [], [], []
     off = jnp.zeros(n, I32)
     drops = jnp.zeros((), I32)
     for d in descs:
@@ -308,20 +297,10 @@ def commit_queue(s: SimState, cfg: SimConfig, descs: List[Desc]):
         drops = drops + jnp.sum((d.valid & ~ok).astype(I32))
         pkt = (pkt_ctr + off) & (cfg.pkt_wrap - 1)
         nfl = jnp.asarray(FLITS_TABLE)[jnp.clip(d.typ, 0, len(FLITS_OF) - 1)]
-        rows.append(jnp.stack([d.typ, d.dst, d.osrc, d.tag, pkt, nfl],
-                              axis=-1))
-        offs.append(off)
-        accs.append(ok)
+        row = jnp.stack([d.typ, d.dst, d.osrc, d.tag, pkt, nfl], axis=-1)
+        q_desc = node_set(q_desc, (s.q_head + q_size + off) % qp, ok, row)
         off = off + ok.astype(I32)
 
-    acc = jnp.stack(accs, axis=1)                       # (N, D)
-    pos = jnp.stack([(s.q_head + q_size + o) % qp for o in offs], axis=1)
-    pos = jnp.where(acc, pos, qp)                       # sink slot
-    row = jnp.stack(rows, axis=1)                       # (N, D, 6)
-    # rejected rows land in the sink slot (index qp); it is never read —
-    # injection only indexes q_head % qp — so it is left dirty on purpose
-    # (zeroing it cost a full q_desc rewrite per commit)
-    q_desc = s.q_desc.at[node[:, None], pos].set(row)
     stats = bump(s.stats, "send_drop", drops)
     return s._replace(q_desc=q_desc, q_size=q_size + off,
                       pkt_ctr=pkt_ctr + off, stats=stats)
@@ -340,15 +319,14 @@ def _l1_install_would_wb(s: SimState, cfg: SimConfig, ctx: NodeCtx,
                          mask: jnp.ndarray, addr: jnp.ndarray) -> jnp.ndarray:
     """Need probe: would :func:`install_l1` send a remote victim
     write-back?  Pure reads — mirrors install_l1's victim selection
-    (first invalid way, else LRU) without the install scatters; must stay
+    (first invalid way, else LRU) without the install writes; must stay
     in sync with it (and with ``ref_serial._exact_need``'s RA branch)."""
-    node = jnp.arange(addr.shape[0], dtype=I32)
     _, si, _, present_any = l1_probe(s, cfg, jnp.where(mask, addr, -1))
     need_i = mask & ~present_any
-    tags = s.l1_tag[node, si]
+    tags = node_get(s.l1_tag, si)
     has_inv = jnp.any(tags < 0, axis=1)
-    lru_way = jnp.argmin(s.l1_lru[node, si], axis=1).astype(I32)
-    vowner = s.l1_owner[node, si, lru_way]
+    lru_way = jnp.argmin(node_get(s.l1_lru, si), axis=1).astype(I32)
+    vowner = node_get(s.l1_owner, (si, lru_way))
     return need_i & ~has_inv & (vowner >= 0) & (vowner != ctx.node_id)
 
 
@@ -357,21 +335,20 @@ def _l2_install_du_count(s: SimState, cfg: SimConfig, ctx: NodeCtx,
     """Need probe: how many remote directory updates (DU packets) would
     :func:`install_l2` enqueue?  Pure reads — mirrors install_l2's
     victim selection (invalid way, else non-migrating LRU, else fail)
-    without the install scatters; must stay in sync with it (and with
+    without the install writes; must stay in sync with it (and with
     ``ref_serial._exact_need``'s B2 branch)."""
-    node = jnp.arange(tag2.shape[0], dtype=I32)
     nid = ctx.node_id
     si, _, present_any = l2_probe(s, cfg, jnp.where(mask, tag2, -1))
     need_i = mask & ~present_any
-    tags = s.l2_tag[node, si]
-    migf = s.l2_mig[node, si]
+    tags = node_get(s.l2_tag, si)
+    migf = node_get(s.l2_mig, si)
     has_inv = jnp.any(tags < 0, axis=1)
-    lru_key = s.l2_lru[node, si] + migf * BIG
+    lru_key = node_get(s.l2_lru, si) + migf * BIG
     lru_way = jnp.argmin(lru_key, axis=1).astype(I32)
     all_mig = jnp.all(migf > 0, axis=1)
     do = need_i & ~(~has_inv & all_mig)           # install fails when every
     vic_valid = do & ~has_inv                     # way is pinned migrating
-    vtag = tags[node, lru_way]
+    vtag = node_get(tags, lru_way)
     duv = vic_valid & (dir_home_v(cfg, vtag, s.knob_central) != nid)
     dun = do & (dir_home_v(cfg, tag2, s.knob_central) != nid)
     return duv.astype(I32) + dun.astype(I32)
@@ -379,7 +356,6 @@ def _l2_install_du_count(s: SimState, cfg: SimConfig, ctx: NodeCtx,
 
 def phase1a(s: SimState, cfg: SimConfig, ctx: NodeCtx) -> SimState:
     n = ctx.node_id.shape[0]
-    node = jnp.arange(n, dtype=I32)
     nid = ctx.node_id
     stats = s.stats
 
@@ -417,9 +393,9 @@ def phase1a(s: SimState, cfg: SimConfig, ctx: NodeCtx) -> SimState:
     if cfg.pc_depth > 1:
         req_hit_p = p_req & l2hit_any
         mig_ok_p = (req_hit_p & (s.knob_mig > 0) & (osrc != nid)
-                    & (s.l2_mig[node, si, hw] == 0))
-        streak_p = jnp.where(s.l2_last[node, si, hw] == osrc,
-                             s.l2_streak[node, si, hw] + 1, 1)
+                    & (node_get(s.l2_mig, (si, hw)) == 0))
+        streak_p = jnp.where(node_get(s.l2_last, (si, hw)) == osrc,
+                             node_get(s.l2_streak, (si, hw)) + 1, 1)
         trig_p = mig_ok_p & (streak_p >= s.knob_mig_thr)
         ra_ok_p = p_ra & (s.st == ST_WAIT_DATA)
         ra_wb_p = _l1_install_would_wb(s, cfg, ctx, ra_ok_p, s.pend_addr)
@@ -474,22 +450,19 @@ def phase1a(s: SimState, cfg: SimConfig, ctx: NodeCtx) -> SimState:
     d0 = merge_desc(d0, Desc(req_hit, jnp.full(n, MSG_RA, I32), osrc, osrc, tag))
 
     mig_ok = (req_hit & (s.knob_mig > 0) & (osrc != nid)
-              & (l2_mig[node, si, hw] == 0))
-    streak_new = jnp.where(l2_last[node, si, hw] == osrc,
-                           l2_streak[node, si, hw] + 1, 1)
-    l2_last = l2_last.at[node, si, hw].set(
-        jnp.where(mig_ok, osrc, l2_last[node, si, hw]))
-    l2_streak = l2_streak.at[node, si, hw].set(
-        jnp.where(mig_ok, streak_new, l2_streak[node, si, hw]))
+              & (node_get(l2_mig, (si, hw)) == 0))
+    streak_new = jnp.where(node_get(l2_last, (si, hw)) == osrc,
+                           node_get(l2_streak, (si, hw)) + 1, 1)
+    l2_last = node_set(l2_last, (si, hw), mig_ok, osrc)
+    l2_streak = node_set(l2_streak, (si, hw), mig_ok, streak_new)
     trig = mig_ok & (streak_new >= s.knob_mig_thr)
-    l2_mig = l2_mig.at[node, si, hw].set(
-        jnp.where(trig, 1, l2_mig[node, si, hw]))
+    l2_mig = node_set(l2_mig, (si, hw), trig, 1)
     d1 = merge_desc(d1, Desc(trig, jnp.full(n, MSG_B2, I32), osrc, nid, tag))
     stats = bump(stats, "migrations", trig)
 
     fwd_hm = (fwd_tag == tag[:, None]) & req_miss[:, None]
     fwd_found = jnp.any(fwd_hm, axis=1)
-    fwd_to = fwd_dst[node, jnp.argmax(fwd_hm, axis=1)]
+    fwd_to = node_get(fwd_dst, jnp.argmax(fwd_hm, axis=1).astype(I32))
     redir = req_miss & fwd_found & (fwd_to >= 0) & (fwd_to != nid)
     trap = req_miss & ~redir
     d0 = merge_desc(d0, Desc(redir, jnp.full(n, MSG_REQ_FWD, I32), fwd_to, osrc, tag))
@@ -566,23 +539,18 @@ def phase1a(s: SimState, cfg: SimConfig, ctx: NodeCtx) -> SimState:
     stats = bump(stats, "l2_install_drop", ins2.n_drops)
 
     # ---- MIG_ACK (S13) ----
-    ak_succ = is_ack & (osrc >= 0) & l2hit & (l2_mig[node, si, hw] > 0)
-    l2_tag = l2_tag.at[node, si, hw].set(
-        jnp.where(ak_succ, -1, l2_tag[node, si, hw]))
-    l2_mig = l2_mig.at[node, si, hw].set(
-        jnp.where(ak_succ, 0, l2_mig[node, si, hw]))
+    ak_succ = (is_ack & (osrc >= 0) & l2hit
+               & (node_get(l2_mig, (si, hw)) > 0))
+    l2_tag = node_set(l2_tag, (si, hw), ak_succ, -1)
+    l2_mig = node_set(l2_mig, (si, hw), ak_succ, 0)
     ak_ins = is_ack & (osrc >= 0)
     p = fwd_ptr % cfg.fwd_entries
-    fwd_tag = fwd_tag.at[node, p].set(
-        jnp.where(ak_ins, tag, fwd_tag[node, p]))
-    fwd_dst = fwd_dst.at[node, p].set(
-        jnp.where(ak_ins, osrc, fwd_dst[node, p]))
+    fwd_tag = node_set(fwd_tag, p, ak_ins, tag)
+    fwd_dst = node_set(fwd_dst, p, ak_ins, osrc)
     fwd_ptr = jnp.where(ak_ins, p + 1, fwd_ptr)
     ak_fail = is_ack & (osrc < 0) & l2hit
-    l2_mig = l2_mig.at[node, si, hw].set(
-        jnp.where(ak_fail, 0, l2_mig[node, si, hw]))
-    l2_streak = l2_streak.at[node, si, hw].set(
-        jnp.where(ak_fail, 0, l2_streak[node, si, hw]))
+    l2_mig = node_set(l2_mig, (si, hw), ak_fail, 0)
+    l2_streak = node_set(l2_streak, (si, hw), ak_fail, 0)
 
     # ---- directory scatters (disjoint per entry — one handler per node,
     # same entry ⇒ same home ⇒ same node) ----
@@ -602,10 +570,9 @@ def phase1a(s: SimState, cfg: SimConfig, ctx: NodeCtx) -> SimState:
     clock = s.lru_clock + any_touch.astype(I32)
     tsi = jnp.where(ins2.did, ins2.touch_set, si)
     twy = jnp.where(ins2.did, ins2.touch_way, hw)
-    l2_lru = s.l2_lru.at[node, tsi, twy].set(
-        jnp.where(l2touch, clock, s.l2_lru[node, tsi, twy]))
-    l1_lru = s.l1_lru.at[node, ins1.touch_set, ins1.touch_way].set(
-        jnp.where(l1touch, clock, s.l1_lru[node, ins1.touch_set, ins1.touch_way]))
+    l2_lru = node_set(s.l2_lru, (tsi, twy), l2touch, clock)
+    l1_lru = node_set(s.l1_lru, (ins1.touch_set, ins1.touch_way), l1touch,
+                      clock)
 
     s = s._replace(
         st=st, ctr=ctr, install_mode=imode, lru_clock=clock,
@@ -642,7 +609,6 @@ def _next_addr(s: SimState, cfg: SimConfig):
 def phase1b(s: SimState, cfg: SimConfig, ctx: NodeCtx) -> SimState:
     n = ctx.node_id.shape[0]
     ca = cfg.cache
-    node = jnp.arange(n, dtype=I32)
     nid = ctx.node_id
     stats = s.stats
     st, ctr = s.st, s.ctr
@@ -769,12 +735,10 @@ def phase1b(s: SimState, cfg: SimConfig, ctx: NodeCtx) -> SimState:
     clock = s.lru_clock + t2.astype(I32)
     t2_l1_set = jnp.where(l1hit, si1, hsi)
     t2_l1_way = jnp.where(l1hit, hw1, hhw)
-    l1_lru = s.l1_lru.at[node, t2_l1_set, t2_l1_way].set(
-        jnp.where(t2_l1, clock, s.l1_lru[node, t2_l1_set, t2_l1_way]))
+    l1_lru = node_set(s.l1_lru, (t2_l1_set, t2_l1_way), t2_l1, clock)
     t2_l2_set = jnp.where(l2f_touch, si2f, ins2.touch_set)
     t2_l2_way = jnp.where(l2f_touch, hw2f, ins2.touch_way)
-    l2_lru = s.l2_lru.at[node, t2_l2_set, t2_l2_way].set(
-        jnp.where(t2_l2, clock, s.l2_lru[node, t2_l2_set, t2_l2_way]))
+    l2_lru = node_set(s.l2_lru, (t2_l2_set, t2_l2_way), t2_l2, clock)
 
     # ---- install_l1 (touch site 3): L2_WAIT refill, WAIT_MEM installs ----
     il1_mask = l2w_fire | wm_fire
@@ -789,8 +753,8 @@ def phase1b(s: SimState, cfg: SimConfig, ctx: NodeCtx) -> SimState:
     stats = bump(stats, "wb_sent", ins1.n_wb_sent)
     stats = bump(stats, "wb_miss", ins1.n_wb_miss)
     clock = clock + ins1.touch.astype(I32)
-    l1_lru = l1_lru.at[node, ins1.touch_set, ins1.touch_way].set(
-        jnp.where(ins1.touch, clock, l1_lru[node, ins1.touch_set, ins1.touch_way]))
+    l1_lru = node_set(l1_lru, (ins1.touch_set, ins1.touch_way), ins1.touch,
+                      clock)
     st = jnp.where(il1_mask, ST_IDLE, st)
 
     s = s._replace(

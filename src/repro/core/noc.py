@@ -26,7 +26,7 @@ from .state import (
     NUM_F, Q_DST, Q_NFL, Q_OSRC, Q_PKT, Q_TAG, Q_TYP,
     R_CNT, R_NFL, R_OSRC, R_PKT, R_SRC, R_TAG, R_TYP,
     P_OSRC, P_SRC, P_TAG, P_TYP, P_VALID,
-    Geometry, NodeCtx, SimState, bump,
+    Geometry, NodeCtx, SimState, bump, node_set,
 )
 
 I32 = jnp.int32
@@ -209,9 +209,8 @@ def deliver(s: SimState, cfg: SimConfig, ctx: NodeCtx, arb: ArbResult,
     prom_pc = jnp.stack([jnp.ones(n, I32), prow[:, R_TYP], prow[:, R_SRC],
                          prow[:, R_OSRC], prow[:, R_TAG]], axis=-1)
     tail0 = jnp.clip(pc_cnt, 0, depth - 1)
-    pc = s.pc.at[node, tail0].set(
-        jnp.where(can_prom[:, None], prom_pc, s.pc[node, tail0]))
-    rob = rob.at[node, psel].set(jnp.where(can_prom[:, None], 0, prow))
+    pc = node_set(s.pc, tail0, can_prom, prom_pc)
+    rob = node_set(rob, psel, can_prom, 0)
     pc_cnt = pc_cnt + can_prom.astype(I32)
 
     # ---- ejection into ROB / pending queue ----
@@ -244,7 +243,7 @@ def deliver(s: SimState, cfg: SimConfig, ctx: NodeCtx, arb: ArbResult,
     # a completed slot is freed when its completion enters the queue, and
     # kept (count == total: the "parked" marker) when the queue is full
     row = jnp.where((complete_m & ~to_park)[:, None], 0, row)
-    rob = rob.at[node, slot].set(jnp.where(multi[:, None], row, cur))
+    rob = node_set(rob, slot, multi, row)
 
     # park a single-flit completion in a fresh slot (guaranteed free by
     # phase2's ejection gate)
@@ -254,8 +253,7 @@ def deliver(s: SimState, cfg: SimConfig, ctx: NodeCtx, arb: ArbResult,
                           f[:, F_OSRC], jnp.ones(n, I32), jnp.ones(n, I32)],
                          axis=-1)
     single_park = single & to_park
-    rob = rob.at[node, park_idx].set(
-        jnp.where(single_park[:, None], park_row, rob[node, park_idx]))
+    rob = node_set(rob, park_idx, single_park, park_row)
 
     row_pc = jnp.stack([
         to_pc.astype(I32),
@@ -266,8 +264,7 @@ def deliver(s: SimState, cfg: SimConfig, ctx: NodeCtx, arb: ArbResult,
     ], axis=-1)
     row_pc = row_pc * to_pc[:, None].astype(I32)
     tail = jnp.clip(pc_cnt, 0, depth - 1)
-    pc = pc.at[node, tail].set(
-        jnp.where(to_pc[:, None], row_pc, pc[node, tail]))
+    pc = node_set(pc, tail, to_pc, row_pc)
 
     return s._replace(inp=inp_next, rob=rob, pc=pc, stats=stats)
 

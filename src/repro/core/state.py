@@ -32,6 +32,7 @@ from __future__ import annotations
 import functools
 from typing import Dict, NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -200,7 +201,9 @@ def init_state(cfg: SimConfig, trace: np.ndarray) -> SimState:
         fwd_dst=neg("fwd_dst", n, cfg.fwd_entries),
         fwd_ptr=z("fwd_ptr", n),
         inp=z("inp", n, NUM_PORTS, NUM_F),
-        q_desc=z("q_desc", n, cfg.send_queue + 1, NUM_Q),  # +1 = sink slot
+        # the last slot is an old scatter sink: never written or read
+        # (appends are slot selects), kept so the state size is unchanged
+        q_desc=z("q_desc", n, cfg.send_queue + 1, NUM_Q),
         q_head=z("q_head", n), q_size=z("q_size", n), q_fid=z("q_fid", n),
         rob=z("rob", n, cfg.rob_slots, NUM_R),
         pc=z("pc", n, cfg.pc_depth, NUM_P),
@@ -334,6 +337,53 @@ def narrow_state(s: SimState, dtypes: Dict[str, np.dtype]) -> SimState:
             v = jnp.minimum(v, np.iinfo(np.int16).max)
         return v.astype(dt)
     return SimState(**{k: down(k, v) for k, v in s._asdict().items()})
+
+
+def _slot_hit(arr: jnp.ndarray, idx: Tuple[jnp.ndarray, ...]) -> jnp.ndarray:
+    """One-hot of ``idx`` over the slot axes of ``arr`` (node axis first),
+    shaped to broadcast against ``arr``."""
+    k = len(idx)
+    slots = arr.shape[1:1 + k]
+    hit = True
+    for a, i in enumerate(idx):
+        iota = jax.lax.broadcasted_iota(i.dtype, (1,) + slots, a + 1)
+        hit = hit & (iota == i.reshape(i.shape + (1,) * k))
+    return hit.reshape(hit.shape + (1,) * (arr.ndim - 1 - k))
+
+
+def node_get(arr: jnp.ndarray, idx) -> jnp.ndarray:
+    """Per-node read ``arr[n, *idx[n]]``, the read that goes with
+    :func:`node_set`: the one-hot select of ``idx`` summed over the slot
+    axes.  Shapes as in :func:`node_set`; the result is ``(N, *fields)``
+    for a full index and ``(N, *rest)`` for a partial one (a whole set
+    of ways)."""
+    idx = idx if isinstance(idx, tuple) else (idx,)
+    picked = jnp.where(_slot_hit(arr, idx), arr, 0)
+    return jnp.sum(picked, axis=tuple(range(1, 1 + len(idx))),
+                   dtype=arr.dtype)
+
+
+def node_set(arr: jnp.ndarray, idx, mask: jnp.ndarray, val) -> jnp.ndarray:
+    """Per-node masked write: ``arr[n, *idx[n]] = val[n]`` where ``mask[n]``.
+
+    ``arr`` is ``(N, *slots, *fields)``; ``idx`` is one ``(N,)`` index
+    array, or a tuple of them, into the slot axes after the node axis;
+    ``val`` is a scalar, ``(N,)``, or ``(N, *fields)`` for a write of a
+    whole trailing field row.  Indices must be in range.
+
+    The same write as ``arr.at[node, *idx].set(where(mask, val,
+    arr[node, *idx]))``, done as a dense one-hot select over the slot
+    axes instead of an XLA scatter (docs/architecture.md "State layout
+    and memory budget").
+    """
+    idx = idx if isinstance(idx, tuple) else (idx,)
+    k = len(idx)
+    hit = _slot_hit(arr, idx) & mask.reshape(
+        mask.shape + (1,) * (arr.ndim - 1))
+    val = jnp.asarray(val, arr.dtype)
+    if val.ndim:
+        val = val.reshape(val.shape[:1] + (1,) * k + val.shape[1:])
+    return jnp.where(hit, val, arr)
 
 
 def state_bytes(cfg: SimConfig, trace_len: int = 200,

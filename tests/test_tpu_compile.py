@@ -74,9 +74,17 @@ def test_router_kernel_compiles_for_v5e_at_paper_scale(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_sim_loop_with_pallas_router_compiles_for_one_v5e(one_chip):
-    """The whole run loop lowered for a v5e carries the compiled router
-    kernel (not its interpreted twin) and fits one chip's memory."""
+#: the 64x64 run loop's planned temp plus output bytes before per-node
+#: state was written by slot selects (compiled for a described v5e by the
+#: TPU compiler installed with jax 0.9.0): 478,382,592 temp + 14,539,264
+#: out.  With the selects it plans 143,458,304 + 14,539,264.
+LOOP64_TEMP_PLUS_OUT_BEFORE_SELECTS = 492_921_856
+
+
+@pytest.fixture(scope="module")
+def loop64(one_chip):
+    """The 64x64 packed run loop with the Pallas router, compiled once
+    for a described v5e; ``(cfg, compiled)``."""
     from repro.core.sim import _run_jit
     cfg = SimConfig(rows=64, cols=64, centralized_directory=False,
                     state_dtype_policy="packed", use_pallas_router=True)
@@ -85,12 +93,36 @@ def test_sim_loop_with_pallas_router_compiles_for_one_v5e(one_chip):
         jax.ShapeDtypeStruct((cfg.num_nodes, 20), jnp.int32))
     state = _shapes(state, jax.tree.map(lambda _: one_chip, state))
     cap = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    compiled = _run_jit.lower(state, cfg, cap, 1).compile()
+    return cfg, _run_jit.lower(state, cfg, cap, 1).compile()
+
+
+def test_sim_loop_with_pallas_router_compiles_for_one_v5e(loop64):
+    """The whole run loop lowered for a v5e carries the compiled router
+    kernel (not its interpreted twin) and fits one chip's memory."""
+    _, compiled = loop64
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < need < V5E_HBM_BYTES, need
+
+
+def test_sim_loop_writes_per_node_state_without_scatters(loop64):
+    """Per-node state is written by dense slot selects
+    (``state.node_set``): the only scatters left in the run loop are the
+    directory's (``dir_loc`` is indexed by tag across the mesh), and the
+    loop plans no more temp memory than it did with per-node scatters."""
+    import re
+    from repro.core.state import dir_shape
+    cfg, compiled = loop64
+    shapes = re.findall(r"= [a-z0-9]+\[([0-9,]*)\]\S* scatter\(",
+                        compiled.as_text())
+    sizes = {int(np.prod([int(d) for d in shp.split(",") if d]))
+             for shp in shapes}
+    assert shapes and sizes == {int(np.prod(dir_shape(cfg)))}, shapes
+    mem = compiled.memory_analysis()
+    planned = mem.temp_size_in_bytes + mem.output_size_in_bytes
+    assert planned <= LOOP64_TEMP_PLUS_OUT_BEFORE_SELECTS, planned
 
 
 def test_sharded_step_compiles_for_v5e_2x2(topo):
