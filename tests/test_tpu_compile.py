@@ -14,7 +14,11 @@ pytest-xdist workers must all collect the same tests.  The persistent
 compile cache is off around these compiles (an entry written for a
 described chip cannot be read back without one).
 """
+import importlib.util
 import os
+import re
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -125,12 +129,12 @@ def test_sim_loop_writes_per_node_state_without_scatters(loop64):
     assert planned <= LOOP64_TEMP_PLUS_OUT_BEFORE_SELECTS, planned
 
 
-def test_sharded_step_compiles_for_v5e_2x2(topo):
-    """The spatial shard_map step over a described 2x2 chip mesh: its
-    phase-3 halo exchange lowers to collective-permutes."""
+def _sharded_step(topo, rows, cols, n_cycles):
+    """The spatial shard_map step over a described 2x2 chip mesh,
+    compiled."""
     from repro.core.sharded import make_sharded_step, state_specs, to_grid
     from repro.core.state import make_geometry
-    cfg = SimConfig(rows=64, cols=64, centralized_directory=False,
+    cfg = SimConfig(rows=rows, cols=cols, centralized_directory=False,
                     dir_layout="home", state_dtype_policy="packed")
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
     grid_spec = NamedSharding(mesh, P("data", "model"))
@@ -146,9 +150,39 @@ def test_sharded_step_compiles_for_v5e_2x2(topo):
     geo_args = [jax.ShapeDtypeStruct(rc, jnp.int32, sharding=grid_spec)] * 3
     geo_args.append(jax.ShapeDtypeStruct(rc + (4,), geo.valid_port.dtype,
                                          sharding=grid_spec))
-    step = make_sharded_step(cfg, mesh)(8)
-    compiled = step.lower(state, *geo_args).compile()
+    step = make_sharded_step(cfg, mesh)(n_cycles)
+    return step.lower(state, *geo_args).compile()
+
+
+def test_sharded_step_compiles_for_v5e_2x2(topo):
+    """The spatial shard_map step over a described 2x2 chip mesh: its
+    phase-3 halo exchange lowers to collective-permutes."""
+    compiled = _sharded_step(topo, 64, 64, 8)
     text = compiled.as_text()
     assert "collective-permute" in text
     mem = compiled.memory_analysis()
     assert 0 < mem.argument_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_halo_bytes_of_the_benchmark_are_the_compiled_permutes(topo):
+    """``halo_ici_share.2x2`` counts the bytes of the halo exchange from
+    the mesh's shape: they are the operands of the four collective
+    permutes the step compiles to, here over unequal 12x20 tiles."""
+    root = Path(__file__).resolve().parent.parent
+    if str(root) not in sys.path:   # the reader imports chipbench
+        sys.path.insert(0, str(root))
+    path = root / "chipbench" / "metrics" / "halo_ici_share.2x2.py"
+    spec = importlib.util.spec_from_file_location("halo_ici_share_2x2", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+
+    compiled = _sharded_step(topo, 24, 40, 8)
+    # `%collective-permute-start.1 = (s32[1,20,10]{...}, s32[...], ...)`
+    # (or the synchronous form): the first shape is the slab sent
+    shapes = re.findall(
+        r"= \(?s32\[([\d,]*)\][^\n]*?collective-permute(?:-start)?\(",
+        compiled.as_text())
+    assert len(shapes) == 4, shapes
+    sent = sum(4 * int(np.prod([int(d) for d in shp.split(",")]))
+               for shp in shapes)
+    assert sent == reader.halo_bytes_per_cycle(24, 40)
