@@ -33,6 +33,10 @@ def arbitrate_ref(age: jnp.ndarray, valid: jnp.ndarray, we: jnp.ndarray,
     Returns:
       assigned: (N, S) port index in 0..3, or -1 for invalid candidates.
       deflect:  (N, S) bool — candidate did not get its first preference.
+
+    The chosen candidate's row is read by a one-hot select over the slots
+    (as in ``state.node_get``), never a gather: a TPU gather of it costs
+    about 1.8 ms at 208x208.
     """
     n, s_ = age.shape
     ports = jnp.arange(4, dtype=I32)
@@ -41,16 +45,14 @@ def arbitrate_ref(age: jnp.ndarray, valid: jnp.ndarray, we: jnp.ndarray,
     # priority key: age desc, slot asc (injection = last slot, loses ties)
     key = jnp.where(valid, age * 8 + (s_ + 2 - slot), -1)
 
-    # PMDR preference scores (S9): lower = preferred.  A desired direction
-    # only scores if the port exists (matches serial `_prefs` vp filter; for
-    # in-mesh destinations the desired port always exists).
-    score = jnp.broadcast_to(10 + ports[None, None, :], (n, s_, 4))
-    score = score.at[:, :, 1].set(jnp.where(dc > 0, 0, score[:, :, 1]))
-    score = score.at[:, :, 3].set(jnp.where(dc < 0, 0, score[:, :, 3]))
-    score = score.at[:, :, 2].set(jnp.where(dr > 0, 1, score[:, :, 2]))
-    score = score.at[:, :, 0].set(jnp.where(dr < 0, 1, score[:, :, 0]))
-    score = jnp.where(vp[:, None, :], score, 1000)
-    first_pref = jnp.argmin(score, axis=2).astype(I32)
+    # PMDR preference scores (S9) per port 0..3: lower = preferred; a
+    # desired direction scores 0 (X) or 1 (Y), any other port 10 + port.  A
+    # desired direction only scores if the port exists (matches serial
+    # `_prefs` vp filter; for in-mesh destinations it always exists).
+    score = jnp.stack([jnp.where(dr < 0, 1, 10), jnp.where(dc > 0, 0, 11),
+                       jnp.where(dr > 0, 1, 12), jnp.where(dc < 0, 0, 13)],
+                      axis=-1).astype(I32)
+    score = jnp.where(vp[:, None, :], score, 1000)           # (N, S, 4)
 
     taken = jnp.zeros((n, 4), bool)
     done = ~valid
@@ -60,15 +62,15 @@ def arbitrate_ref(age: jnp.ndarray, valid: jnp.ndarray, we: jnp.ndarray,
         kk = jnp.where(done, -1, key)
         best = jnp.argmax(kk, axis=1)
         has = jnp.max(kk, axis=1) >= 0
-        bscore = jnp.take_along_axis(score, best[:, None, None].repeat(4, 2),
-                                     axis=1)[:, 0, :]
+        sel = slot[None, :] == best[:, None]                  # (N, S)
+        bscore = jnp.sum(jnp.where(sel[:, :, None], score, 0), axis=1)
         eff = bscore + taken.astype(I32) * 10000
         port = jnp.argmin(eff, axis=1).astype(I32)
-        onehot_b = (slot[None, :] == best[:, None]) & has[:, None]
+        onehot_b = sel & has[:, None]
         onehot_p = (ports[None, :] == port[:, None]) & has[:, None]
         assigned = jnp.where(onehot_b, port[:, None], assigned)
-        fp = jnp.take_along_axis(first_pref, best[:, None], axis=1)[:, 0]
-        wej = jnp.take_along_axis(we, best[:, None], axis=1)[:, 0]
+        fp = jnp.argmin(bscore, axis=1).astype(I32)           # first pref
+        wej = jnp.any(sel & we, axis=1)
         defl = wej | (port != fp)
         deflect = jnp.where(onehot_b, defl[:, None], deflect)
         taken = taken | onehot_p

@@ -78,6 +78,23 @@ def test_router_kernel_compiles_for_v5e_at_paper_scale(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_xla_arbitration_reads_no_gathers_at_paper_scale(one_chip):
+    """The arbitration every cell runs (``use_pallas_router`` off), for
+    all 43,264 routers: its tables are built and read by dense selects,
+    so it holds no gather and no dynamic-update-slice and plans no temp."""
+    from repro.kernels.ref import arbitrate_ref
+    n = 208 * 208
+    args = [jax.ShapeDtypeStruct((n, 5), dt, sharding=one_chip)
+            for dt in (jnp.int32, jnp.bool_, jnp.bool_, jnp.int32,
+                       jnp.int32)]
+    args.append(jax.ShapeDtypeStruct((n, 4), jnp.bool_, sharding=one_chip))
+    compiled = jax.jit(arbitrate_ref).lower(*args).compile()
+    text = compiled.as_text()
+    assert " gather(" not in text
+    assert " dynamic-update-slice(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 #: the 64x64 run loop's planned temp plus output bytes before per-node
 #: state was written by slot selects (compiled for a described v5e by the
 #: TPU compiler installed with jax 0.9.0): 478,382,592 temp + 14,539,264
