@@ -50,7 +50,7 @@ from repro.core.config import SimConfig                    # noqa: E402
 from repro.core.sharded import ShardedSim                  # noqa: E402
 from repro.core.sim import _run_jit                        # noqa: E402
 from repro.core.state import init_state                    # noqa: E402
-from repro.core.trace import random_trace                  # noqa: E402
+from repro.core.workloads import random_trace              # noqa: E402
 from jax.sharding import Mesh                              # noqa: E402
 
 

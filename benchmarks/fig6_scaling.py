@@ -22,7 +22,7 @@ from repro.bench.collect import (                               # noqa: E402
     count_metric, ratio_metric, timing_metric)
 from repro.core import SimConfig, run                           # noqa: E402
 from repro.core.ref_serial import SerialSim                     # noqa: E402
-from repro.core.trace import app_trace                          # noqa: E402
+from repro.core.workloads import app_trace                      # noqa: E402
 
 
 def one(rows: int, cols: int, refs: int, serial_limit: int):

@@ -40,7 +40,7 @@ from repro.bench import BenchReport, Benchmark, bench_main  # noqa: E402
 from repro.bench.collect import (                           # noqa: E402
     count_metric, flag_metric, health_metrics, ratio_metric, timing_metric)
 from repro.core import SimConfig, run                       # noqa: E402
-from repro.core.trace import app_trace_loop, resolve_trace  # noqa: E402
+from repro.core.workloads import app_trace_loop, resolve_trace  # noqa: E402
 
 
 def bench_plan(args) -> dict:

@@ -36,7 +36,6 @@ AREA_FILES = {
     "fig6": "fig6_scaling.py",
     "table3": "table3_stats.py",
     "table4": "table4_memory.py",
-    "roofline": "roofline.py",
     "paper_scale": "paper_scale.py",
 }
 

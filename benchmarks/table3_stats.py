@@ -18,7 +18,7 @@ from repro.bench import BenchReport, Benchmark, bench_main      # noqa: E402
 from repro.core.engine import enable_compile_cache              # noqa: E402
 from repro.bench.collect import health_metrics                  # noqa: E402
 from repro.core import SimConfig, run                           # noqa: E402
-from repro.core.trace import TRACE_APPS, app_trace              # noqa: E402
+from repro.core.workloads import TRACE_APPS, app_trace          # noqa: E402
 
 COLS = ("req_made", "req_rcvd", "reply_sent", "reply_rcvd", "trap",
         "redirection", "dir_search", "mem_req", "migrations")

@@ -23,7 +23,7 @@ from repro.core.engine import enable_compile_cache              # noqa: E402
 from repro.bench.collect import (                               # noqa: E402
     count_metric, ratio_metric, timing_metric)
 from repro.core import SimConfig                                # noqa: E402
-from repro.core.trace import app_trace, app_trace_loop          # noqa: E402
+from repro.core.workloads import app_trace, app_trace_loop      # noqa: E402
 
 
 def add_args(ap) -> None:

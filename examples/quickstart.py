@@ -8,7 +8,7 @@ sys.path.insert(0, "src")
 
 from repro.core import SimConfig, run
 from repro.core.ref_serial import SerialSim
-from repro.core.trace import app_trace
+from repro.core.workloads import app_trace
 
 
 def main() -> None:

@@ -17,7 +17,7 @@ import jax
 
 from repro.core.config import SimConfig
 from repro.core.sharded import ShardedSim
-from repro.core.trace import app_trace
+from repro.core.workloads import app_trace
 
 
 def main() -> None:

@@ -8,7 +8,7 @@ sys.path.insert(0, "src")
 from repro.core.config import SimConfig, CacheConfig
 from repro.core.ref_serial import SerialSim, STAT_NAMES
 from repro.core.sim import VectorSim
-from repro.core.trace import resolve_trace
+from repro.core.workloads import resolve_trace
 from repro.core import state as S
 
 

@@ -15,8 +15,7 @@ One grammar everywhere (``name`` or ``name:key=val,...`` — see
 :mod:`.base`): :func:`resolve_trace`, :func:`stacked_traces`, manifests,
 ``--app``, the zoo and the generated CLI docs all dispatch through the
 same registry, so registering a generator is the whole job of adding a
-scenario source.  ``repro.core.trace`` remains as a thin back-compat
-shim over this package.
+scenario source.
 """
 from __future__ import annotations
 

@@ -24,9 +24,8 @@ reproduces the exact (cfg, trace) combinations catalogued in ROADMAP
 
 Every generator here is registered as a :class:`~.base.TrafficGen`:
 each app name, ``random``, and the ``loop`` reference family dispatch
-through the shared :func:`~.base.resolve` grammar.  Moving these
-functions out of ``repro.core.trace`` changed NOTHING bit-wise — the
-golden digests in ``tests/test_workloads.py`` pin every output.
+through the shared :func:`~.base.resolve` grammar.  The golden
+digests in ``tests/test_workloads.py`` pin every output bit for bit.
 """
 from __future__ import annotations
 

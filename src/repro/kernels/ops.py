@@ -1,4 +1,4 @@
-"""Jit'd public wrappers for the Pallas kernels with oracle dispatch.
+"""Jit'd public wrappers for the Pallas kernel with oracle dispatch.
 
 ``backend="ref"`` runs the pure-jnp oracle (XLA — also the default inside
 the simulator so HLO cost analysis sees true FLOPs); ``backend="pallas"``
@@ -15,7 +15,6 @@ import functools
 import jax
 
 from . import ref
-from .flash_attention import flash_attention_pallas
 from .router_phase import router_arbitrate_pallas
 
 
@@ -32,10 +31,3 @@ def arbitrate(age, valid, we, dc, dr, vp, backend: str = "ref"):
     if backend == "ref":
         return ref.arbitrate_ref(age, valid, we, dc, dr, vp)
     return _pallas(router_arbitrate_pallas, age, valid, we, dc, dr, vp)
-
-
-def attention(q, k, v, causal: bool = True, backend: str = "ref"):
-    """Multi-head attention. See :func:`repro.kernels.ref.attention_ref`."""
-    if backend == "ref":
-        return ref.attention_ref(q, k, v, causal=causal)
-    return _pallas(flash_attention_pallas, q, k, v, causal=causal)

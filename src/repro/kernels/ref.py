@@ -1,17 +1,14 @@
-"""Pure-jnp oracles for the Pallas kernels.
+"""Pure-jnp oracle for the Pallas router-arbitration kernel.
 
 ``arbitrate_ref`` is the semantic definition of the paper's Phase-2 router
 arbitration (age-priority sort + PMDR port selection + deflection) — the
 vectorized simulator calls it directly, and the Pallas kernel in
 :mod:`repro.kernels.router_phase` must match it bit-for-bit.
-
-``attention_ref`` is the oracle for the blocked flash-attention kernel.
 """
 from __future__ import annotations
 
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 
 I32 = jnp.int32
@@ -77,18 +74,3 @@ def arbitrate_ref(age: jnp.ndarray, valid: jnp.ndarray, we: jnp.ndarray,
         done = done | onehot_b
     return assigned, deflect
 
-
-def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                  causal: bool = True, scale: float | None = None
-                  ) -> jnp.ndarray:
-    """Reference attention. q,k,v: (B, H, S, D) / (B, H, T, D)."""
-    d = q.shape[-1]
-    scale = scale if scale is not None else 1.0 / (d ** 0.5)
-    logits = jnp.einsum("bhsd,bhtd->bhst", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    if causal:
-        s_, t_ = logits.shape[-2:]
-        mask = jnp.tril(jnp.ones((s_, t_), bool), t_ - s_)
-        logits = jnp.where(mask, logits, -1e30)
-    w = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhst,bhtd->bhsd", w, v.astype(jnp.float32)).astype(q.dtype)

@@ -185,7 +185,7 @@ def main(argv=None) -> None:
 
     if args.serial:
         from repro.core.ref_serial import SerialSim
-        from repro.core.trace import resolve_trace
+        from repro.core.workloads import resolve_trace
         tr = resolve_trace(cfg, args.app, args.refs, args.seed)
         t0 = time.time()
         stats = SerialSim(cfg, tr).run()

@@ -33,7 +33,7 @@ def test_composed_single_device_degenerates_to_solo():
     from repro.core.sharded import run_composed
     from repro.core.sim import run
     from repro.core.sweep import ScenarioSpec, SweepSpec
-    from repro.core.trace import app_trace
+    from repro.core.workloads import app_trace
 
     cfg = SimConfig(rows=4, cols=4, addr_bits=14,
                     centralized_directory=False)
@@ -58,7 +58,7 @@ def test_composed_clamps_max_cycles():
     from repro.core.sharded import run_composed
     from repro.core.sim import run
     from repro.core.sweep import ScenarioSpec, SweepSpec
-    from repro.core.trace import app_trace
+    from repro.core.workloads import app_trace
 
     cfg = SimConfig(rows=4, cols=4, addr_bits=14,
                     centralized_directory=False)
@@ -104,7 +104,7 @@ def test_composed_batched_livelock_abort_with_healthy_batchmate():
     import numpy as np
     from repro.core.sharded import ShardedSim
     from repro.core.sim import run
-    from repro.core.trace import app_trace, app_trace_loop
+    from repro.core.workloads import app_trace, app_trace_loop
 
     cfg = SimConfig(rows=16, cols=16, centralized_directory=False,
                     dir_layout="home", max_cycles=30_000, pc_depth=1)
@@ -144,7 +144,7 @@ def test_composed_grid_batch_padding_and_spatial_degeneracy():
         from repro.core.sharded import ShardedSim, run_composed
         from repro.core.sim import run
         from repro.core.sweep import ScenarioSpec, SweepSpec
-        from repro.core.trace import app_trace
+        from repro.core.workloads import app_trace
 
         cfg = SimConfig(rows=8, cols=8, addr_bits=16,
                         centralized_directory=False, migrate_threshold=2)

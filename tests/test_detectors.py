@@ -19,7 +19,7 @@ pin the compatibility escape hatch to keep a real livelock to detect.
 """
 from repro.core.config import SimConfig
 from repro.core.sim import run
-from repro.core.trace import app_trace, app_trace_loop
+from repro.core.workloads import app_trace, app_trace_loop
 
 _DIAG_KEYS = ("circulating_flits", "wait_dir_nodes", "wait_data_nodes",
               "stalled_queues", "flits_to_node0")

@@ -17,7 +17,7 @@ import pytest
 from repro.core import engine
 from repro.core.config import SimConfig
 from repro.core.sim import run, _run_jit
-from repro.core.trace import app_trace, random_trace
+from repro.core.workloads import app_trace, random_trace
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -177,7 +177,7 @@ def test_sharded_backend_via_planner():
         from repro.core.config import SimConfig
         from repro.core import engine
         from repro.core.sim import run
-        from repro.core.trace import app_trace
+        from repro.core.workloads import app_trace
 
         base = SimConfig(rows=8, cols=8, addr_bits=16,
                          centralized_directory=False, migrate_threshold=2)
@@ -217,7 +217,7 @@ def test_composed_backend_mixed_shape_plan_bit_exact():
         from repro.core.config import SimConfig
         from repro.core import engine
         from repro.core.sim import run
-        from repro.core.trace import app_trace
+        from repro.core.workloads import app_trace
 
         base = SimConfig(addr_bits=16, centralized_directory=False)
         scs = [

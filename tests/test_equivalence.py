@@ -12,7 +12,8 @@ from hypothesis import HealthCheck, given, settings, strategies as st
 from repro.core.config import SimConfig
 from repro.core.ref_serial import SerialSim
 from repro.core.sim import VectorSim, run
-from repro.core.trace import app_trace, random_trace
+from repro.core.workloads import (PATTERN_NAMES, TRACE_APPS, app_trace,
+                                  random_trace, resolve_trace)
 
 
 def final_stats_equal(cfg: SimConfig, trace) -> None:
@@ -34,6 +35,28 @@ def test_end_to_end_identical(app, seed, dist):
     tr = (random_trace(cfg, 30, seed) if app == "random"
           else app_trace(cfg, app, 30, seed))
     final_stats_equal(cfg, tr)
+
+
+#: every traffic source: the paper's apps, ``random`` and the NoC patterns.
+SOURCES = (*TRACE_APPS, "random", *PATTERN_NAMES)
+
+#: the directory arrangements the simulator supports.
+DIRECTORIES = {
+    "central_flat": dict(centralized_directory=True, dir_layout="flat"),
+    "dist_flat": dict(centralized_directory=False, dir_layout="flat"),
+    "dist_home": dict(centralized_directory=False, dir_layout="home"),
+}
+
+
+@pytest.mark.parametrize("layout", ["wide", "packed"])
+@pytest.mark.parametrize("directory", list(DIRECTORIES))
+@pytest.mark.parametrize("source", SOURCES)
+def test_dense_matches_serial(source, directory, layout):
+    """Every source, directory arrangement and state layout agrees with
+    the serial golden model."""
+    cfg = SimConfig(rows=4, cols=4, addr_bits=14, migrate_threshold=2,
+                    state_dtype_policy=layout, **DIRECTORIES[directory])
+    final_stats_equal(cfg, resolve_trace(cfg, source, 20, 5))
 
 
 def test_nonsquare_mesh():

@@ -1,13 +1,10 @@
-"""Pallas kernels vs pure-jnp oracles (interpret mode, shape/dtype sweeps)."""
+"""The Pallas router kernel vs its pure-jnp oracle (interpret mode)."""
 import numpy as np
-import pytest
 from hypothesis import HealthCheck, given, settings, strategies as st
 
-import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.ref import arbitrate_ref, attention_ref
+from repro.kernels.ref import arbitrate_ref
 from repro.kernels.router_phase import router_arbitrate_pallas
 from tests.test_noc_properties import random_arb_case
 
@@ -24,75 +21,15 @@ def test_router_kernel_bit_exact(seed, n):
     assert np.array_equal(np.asarray(d0), np.asarray(d1))
 
 
-@pytest.mark.parametrize("b,h,s,d,dtype,causal", [
-    (1, 2, 256, 64, jnp.float32, True),
-    (2, 1, 128, 128, jnp.bfloat16, True),
-    (1, 4, 384, 64, jnp.float32, False),
-    (2, 2, 512, 32, jnp.bfloat16, True),
-])
-def test_flash_attention_kernel(b, h, s, d, dtype, causal):
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((b, h, s, d)), dtype)
-    k = jnp.asarray(rng.standard_normal((b, h, s, d)), dtype)
-    v = jnp.asarray(rng.standard_normal((b, h, s, d)), dtype)
-    o0 = attention_ref(q, k, v, causal=causal)
-    o1 = flash_attention_pallas(q, k, v, causal=causal, interpret=True,
-                                block_q=128, block_k=128)
-    err = float(jnp.max(jnp.abs(o0.astype(jnp.float32)
-                                - o1.astype(jnp.float32))))
-    tol = 5e-2 if dtype == jnp.bfloat16 else 1e-5
-    assert err < tol, err
-
-
-def test_blocked_xla_attention_matches_full():
-    from repro.models.common import _blocked_attention, _mask_logits
-    rng = np.random.default_rng(1)
-    b, s, h, kv, d = 2, 256, 8, 2, 32
-    q = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
-    pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-
-    def full(causal, window):
-        rep = h // kv
-        qg = (q / d ** 0.5).reshape(b, s, kv, rep, d)
-        lg = jnp.einsum("bskrd,btkd->bkrst", qg, k).reshape(b, h, s, s)
-        lg = _mask_logits(lg, pos, pos, causal, window)
-        w = jax.nn.softmax(lg, -1).reshape(b, kv, rep, s, s)
-        return jnp.einsum("bkrst,btkd->bskrd", w, v).reshape(b, s, h, d)
-
-    for causal, window in [(True, 0), (True, 64), (False, 0)]:
-        o1 = full(causal, window)
-        o2 = _blocked_attention(q, k, v, pos, pos, causal, window,
-                                qc=64, kc=32)
-        assert float(jnp.max(jnp.abs(o1 - o2))) < 2e-5
-
-
 def test_pallas_router_inside_simulator():
     """End-to-end: the sim with the Pallas router equals the ref path."""
     import dataclasses
     from repro.core.config import SimConfig
     from repro.core.sim import run
-    from repro.core.trace import app_trace
+    from repro.core.workloads import app_trace
     cfg = SimConfig(rows=3, cols=3, addr_bits=13, migrate_threshold=2)
     tr = app_trace(cfg, "matmul", 15, 1)
     a = run(cfg, tr)
     b = run(dataclasses.replace(cfg, use_pallas_router=True), tr)
     assert a == b
 
-
-def test_banded_window_attention_matches():
-    """Sliding-window banded iteration == full-band blocked attention."""
-    from repro.models.common import _blocked_attention
-    rng = np.random.default_rng(2)
-    for (s, window, qc, kc) in [(512, 96, 64, 32), (1024, 200, 128, 64)]:
-        b, h, kv, d = 2, 4, 2, 16
-        q = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
-        k = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
-        pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-        o1 = _blocked_attention(q, k, v, pos, pos, True, window,
-                                qc=qc, kc=kc, banded=False)
-        o2 = _blocked_attention(q, k, v, pos, pos, True, window,
-                                qc=qc, kc=kc, banded=True)
-        assert float(jnp.max(jnp.abs(o1 - o2))) < 1e-5

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 from repro.core.config import SimConfig
 from repro.core.ref_serial import SerialSim
 from repro.core.sim import run
-from repro.core.trace import app_trace, random_trace
+from repro.core.workloads import app_trace, random_trace
 from repro.kernels.ref import arbitrate_ref
 
 

@@ -21,7 +21,7 @@ import numpy as np
 from repro.core.config import MSG_DA, MSG_DU, SimConfig
 from repro.core.ref_serial import SerialSim
 from repro.core.sim import VectorSim, run
-from repro.core.trace import app_trace, app_trace_loop
+from repro.core.workloads import app_trace, app_trace_loop
 
 # Golden stats captured on the pre-queue seed semantics (single S14
 # register).  pc_depth=1 must reproduce them bit-for-bit.

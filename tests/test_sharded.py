@@ -14,7 +14,7 @@ SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax
     from repro.core.config import SimConfig
-    from repro.core.trace import app_trace
+    from repro.core.workloads import app_trace
     from repro.core.sim import run
     from repro.core.sharded import ShardedSim
 
@@ -61,7 +61,7 @@ def test_sharded_clamps_max_cycles_to_dense_backend():
     from repro.core.config import SimConfig
     from repro.core.sharded import ShardedSim
     from repro.core.sim import run
-    from repro.core.trace import app_trace
+    from repro.core.workloads import app_trace
 
     cfg = SimConfig(rows=4, cols=4, addr_bits=14,
                     centralized_directory=False, dir_layout="home")

@@ -20,7 +20,7 @@ import pytest
 from repro.core.config import SimConfig
 from repro.core.sim import run
 from repro.core.sweep import ScenarioSpec, SweepSpec, run_sweep
-from repro.core.trace import app_trace, random_trace, stacked_traces
+from repro.core.workloads import app_trace, random_trace, stacked_traces
 
 
 def solo(cfg: SimConfig, sc: ScenarioSpec):
@@ -114,7 +114,7 @@ def test_sweep_sharded_over_host_devices():
         from repro.core.config import SimConfig
         from repro.core.sim import run
         from repro.core.sweep import SweepSpec, run_sweep
-        from repro.core.trace import app_trace
+        from repro.core.workloads import app_trace
 
         cfg = SimConfig(rows=4, cols=4, addr_bits=14, migrate_threshold=2,
                         centralized_directory=False)
@@ -146,7 +146,7 @@ def test_sweep_indivisible_batch_pads_across_devices():
         from repro.core.config import SimConfig
         from repro.core.sim import run
         from repro.core.sweep import SweepSpec, run_sweep
-        from repro.core.trace import app_trace
+        from repro.core.workloads import app_trace
 
         cfg = SimConfig(rows=4, cols=4, addr_bits=14, migrate_threshold=2,
                         centralized_directory=False)

@@ -3,7 +3,7 @@ import numpy as np
 
 from repro.core.config import SimConfig
 from repro.core.sim import run
-from repro.core.trace import TRACE_APPS, app_trace
+from repro.core.workloads import TRACE_APPS, app_trace
 
 
 def test_paper_table3_shape():

@@ -2,13 +2,13 @@
 
 Two levels of parity:
 
-* :func:`repro.core.trace.from_model_schedule` is **bit-identical** to the
+* :func:`repro.core.workloads.from_model_schedule` is **bit-identical** to the
   original loop (the only random draws are the activation block indices,
   and numpy's bounded-integer sampling consumes the PCG64 stream the same
   way scalar-by-scalar and in blocks).
-* :func:`repro.core.trace.app_trace` draws its streams in a different
+* :func:`repro.core.workloads.app_trace` draws its streams in a different
   (blocked, per-slab) order than the loop reference
-  :func:`repro.core.trace.app_trace_loop`, so arrays differ element-wise;
+  :func:`repro.core.workloads.app_trace_loop`, so arrays differ element-wise;
   equivalence is asserted at the distribution level — region mix, zipf
   concentration of the shared region, hot-set reuse — which is what the
   simulator's traffic actually depends on.
@@ -16,8 +16,9 @@ Two levels of parity:
 import numpy as np
 
 from repro.core.config import SimConfig
-from repro.core.trace import (app_trace, app_trace_loop, from_model_schedule,
-                              random_trace, stacked_traces, TRACE_APPS)
+from repro.core.workloads import (app_trace, app_trace_loop,
+                                  from_model_schedule, random_trace,
+                                  stacked_traces, TRACE_APPS)
 
 
 def _fms_loop_reference(cfg, layer_params_bytes, d_model, n_layers,

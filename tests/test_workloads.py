@@ -2,9 +2,9 @@
 
 Four contracts:
 
-1. **Golden bit-identity** — moving the generators out of
-   ``repro.core.trace`` changed nothing: every app/loop/random/model
-   output hashes to its pre-refactor digest.
+1. **Golden bit-identity** — every app/loop/random/model generator
+   output hashes to a pinned digest, unchanged since the generators
+   moved into this package.
 2. **Registry consistency** — validation and dispatch share one parser,
    so every source spec ``valid_app`` accepts is resolvable (the old
    ``valid_app("loop:random")``-accepts / ``resolve_trace``-raises
@@ -32,9 +32,9 @@ import pytest
 
 from repro.core import workloads as W
 from repro.core.config import SimConfig
-from repro.core.trace import (TRACE_APPS, app_trace, app_trace_loop,
-                              from_model_schedule, random_trace,
-                              resolve_trace, stacked_traces, valid_app)
+from repro.core.workloads import (TRACE_APPS, app_trace, app_trace_loop,
+                                  from_model_schedule, random_trace,
+                                  resolve_trace, stacked_traces, valid_app)
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
